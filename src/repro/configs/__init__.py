@@ -27,6 +27,8 @@ _MODULES = {
     # the paper's evaluation models
     "qwen3-30b-a3b": "qwen3_30b_a3b",
     "gpt-oss-20b": "gpt_oss_20b",
+    # depth cut to one TPU v5e chip, widths as published
+    "qwen3-30b-a3b-1chip": "qwen3_30b_a3b_1chip",
 }
 
 ASSIGNED = list(_MODULES)[:10]

@@ -12,9 +12,11 @@ KV tiles.
 
 ``paged_decode_attention_pallas`` is the page-table-aware variant backing
 the PagedKVAllocator's scattered physical layout: K/V live in a global
-``(n_pages, page_size, Hkv, hd)`` pool and each sequence's *block table*
-(scalar-prefetched, so the index maps can read it before the body runs)
-names the physical pages holding its KV in logical order.  Grid is (batch,
+``(Hkv, n_pages, page_size, hd)`` pool (head-major, the layout of JAX's own
+paged-attention kernel: one page of one kv head is a ``(page_size, hd)``
+tile, which the TPU's (8|16, 128) tiling accepts) and each sequence's
+*block table* (scalar-prefetched, so the index maps can read it before the
+body runs) names the physical pages holding its KV in logical order.  Grid is (batch,
 kv_heads, max_pages): the DMA engine streams exactly the pages the block
 table names — one page per grid step — while online-softmax state persists
 in VMEM scratch across the page axis, exactly the structure of the slot
@@ -48,7 +50,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, kv_blk: int,
                    scale: float, max_len: int, window: Optional[int]):
     q = q_ref[0, 0].astype(jnp.float32) * scale            # (g, hd)
     g = q.shape[0]
-    length = len_ref[0]                                    # valid kv entries
+    length = len_ref[pl.program_id(0)]                     # valid kv entries
 
     n_kv = max_len // kv_blk
     hi = jnp.minimum((length + kv_blk - 1) // kv_blk, n_kv)
@@ -105,16 +107,22 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
 
     kernel = functools.partial(_decode_kernel, kv_blk=kv_blk, scale=scale,
                                max_len=s_max, window=window)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                 # lengths
         grid=(b, hkv),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi: (bi,)),
-            pl.BlockSpec((1, 1, g, hd), lambda bi, hi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, s_max, hd), lambda bi, hi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, s_max, hd), lambda bi, hi: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, g, hd), lambda bi, hi, lens: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, s_max, hd),
+                         lambda bi, hi, lens: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, s_max, hd),
+                         lambda bi, hi, lens: (bi, hi, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bi, hi: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, hd),
+                               lambda bi, hi, lens: (bi, hi, 0, 0)),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qg, kt, vt)
@@ -145,8 +153,8 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(pi < n_seq_pages)
     def _page():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (g, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                # (page, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = q @ k.T                                        # (g, page)
         kv_pos = pi * page_size + jax.lax.iota(jnp.int32, page_size)
         mask = kv_pos[None, :] < length
@@ -187,8 +195,8 @@ def _paged_verify_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(pi < n_seq_pages)
     def _page():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (W*g, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                # (page, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = q @ k.T                                        # (W*g, page)
         kv_pos = pi * page_size + jax.lax.iota(jnp.int32, page_size)
         # row r holds window query w = r // g; its causal KV horizon is
@@ -222,12 +230,12 @@ def paged_verify_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   interpret: bool = False) -> jax.Array:
     """Verify-window paged attention.  q: (B, W, H, hd) — the W = k+1
     window query tokens per sequence, oldest first; k_pages/v_pages:
-    (n_pages, page_size, Hkv, hd) global pool; block_tables:
+    (Hkv, n_pages, page_size, hd) global pool; block_tables:
     (B, max_pages) int32; lengths: (B,) int32 valid KV tokens INCLUDING
     all W window tokens' K/V already written.  Returns (B, W, H, hd).
     Each sequence's KV stream is read once for the whole window."""
     b, win, h, hd = q.shape
-    n_pages, page_size, hkv, _ = k_pages.shape
+    hkv, n_pages, page_size, _ = k_pages.shape
     g = h // hkv
     max_pages = block_tables.shape[1]
     assert block_tables.shape == (b, max_pages), block_tables.shape
@@ -249,12 +257,12 @@ def paged_verify_attention_pallas(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, rows, hd),
                          lambda bi, hi, pi, lens, bt: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
+            pl.BlockSpec((1, 1, page_size, hd),
                          lambda bi, hi, pi, lens, bt:
-                         (bt[bi * max_pages + pi], 0, hi, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
+                         (hi, bt[bi * max_pages + pi], 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
                          lambda bi, hi, pi, lens, bt:
-                         (bt[bi * max_pages + pi], 0, hi, 0)),
+                         (hi, bt[bi * max_pages + pi], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, hd),
                                lambda bi, hi, pi, lens, bt: (bi, hi, 0, 0)),
@@ -281,14 +289,14 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   window: Optional[int] = None,
                                   scale: Optional[float] = None,
                                   interpret: bool = False) -> jax.Array:
-    """q: (B, H, hd); k_pages/v_pages: (n_pages, page_size, Hkv, hd) —
+    """q: (B, H, hd); k_pages/v_pages: (Hkv, n_pages, page_size, hd) —
     the global page pool; block_tables: (B, max_pages) int32 physical page
     ids in logical order (entries past a sequence's page count are ignored
     but must be valid indices — pad with 0); lengths: (B,) int32 valid KV
     tokens INCLUDING the new token's K/V already written.
     Returns (B, H, hd)."""
     b, h, hd = q.shape
-    n_pages, page_size, hkv, _ = k_pages.shape
+    hkv, n_pages, page_size, _ = k_pages.shape
     g = h // hkv
     max_pages = block_tables.shape[1]
     assert block_tables.shape == (b, max_pages), block_tables.shape
@@ -306,12 +314,12 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd),
                          lambda bi, hi, pi, lens, bt: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
+            pl.BlockSpec((1, 1, page_size, hd),
                          lambda bi, hi, pi, lens, bt:
-                         (bt[bi * max_pages + pi], 0, hi, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
+                         (hi, bt[bi * max_pages + pi], 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
                          lambda bi, hi, pi, lens, bt:
-                         (bt[bi * max_pages + pi], 0, hi, 0)),
+                         (hi, bt[bi * max_pages + pi], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
                                lambda bi, hi, pi, lens, bt: (bi, hi, 0, 0)),

@@ -1,9 +1,11 @@
 """Jit-wrapped public entry points for the Pallas kernels.
 
-On CPU (this container) the kernels execute through Pallas interpret mode —
-bit-accurate algorithm validation without a TPU. On TPU backends they lower
-to Mosaic. ``interpret`` is auto-detected from the default backend; padding
-to tile multiples happens here so the kernels stay shape-strict.
+Every wrapper lowers to Mosaic for the TPU unless the caller passes
+``interpret=True``, which runs the kernel through the Pallas interpreter
+(how the CPU tests validate the algorithms). There is no silent fallback:
+a kernel called on a backend other than the TPU without ``interpret=True``
+fails when it is lowered. Padding to tile multiples happens here so the
+kernels stay shape-strict.
 
 The model plugs these in via ``gmm_fn=`` (MoE) or by swapping the attention
 reference path; correctness of the swap is covered by tests/test_kernels.py.
@@ -25,10 +27,6 @@ from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.kernels.moe_gmm_ragged import moe_gmm_ragged_pallas
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pad_to(x: jax.Array, axis: int, mult: int):
     n = x.shape[axis]
     pad = (-n) % mult
@@ -44,10 +42,9 @@ def _pad_to(x: jax.Array, axis: int, mult: int):
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, q_blk: int = 128,
                     kv_blk: int = 128,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Padding-safe wrapper: pads S to tile multiples; padded queries are
     discarded, padded keys are causally masked out (pos > any real q)."""
-    interpret = _auto_interpret() if interpret is None else interpret
     s0 = q.shape[1]
     blk = max(q_blk, kv_blk)
     q_p, _ = _pad_to(q, 1, blk)
@@ -66,8 +63,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
 @functools.partial(jax.jit, static_argnames=("window", "kv_blk", "interpret"))
 def decode_attention(q, k_cache, v_cache, lengths, *,
                      window: Optional[int] = None, kv_blk: int = 128,
-                     interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _auto_interpret() if interpret is None else interpret
+                     interpret: bool = False) -> jax.Array:
     k_p, _ = _pad_to(k_cache, 1, kv_blk)
     v_p, _ = _pad_to(v_cache, 1, kv_blk)
     return decode_attention_pallas(q, k_p, v_p, lengths, window=window,
@@ -77,14 +73,13 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            window: Optional[int] = None,
-                           interpret: Optional[bool] = None) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """Decode attention over the PagedKVAllocator's scattered physical
     layout: ``block_tables`` (B, max_pages) holds each sequence's physical
     page ids (``PagedKVAllocator.block_table``, padded with 0 — any valid
     page id works, padded entries are masked by ``lengths``). Page count
     and size come from the pool shape; no padding is needed because pages
     are fixed-size by construction."""
-    interpret = _auto_interpret() if interpret is None else interpret
     return paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
                                          lengths, window=window,
                                          interpret=interpret)
@@ -93,13 +88,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_verify_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            window: Optional[int] = None,
-                           interpret: Optional[bool] = None) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """Speculative verify-k attention over the paged pool: ``q`` is a
     (B, W, H, hd) window of W = k+1 query tokens per sequence (oldest
     first) whose K/V have already been written; ``lengths`` counts valid
     KV INCLUDING the window.  One KV stream per sequence serves the whole
     window — the dispatch-amortization the speculative scheduler rides."""
-    interpret = _auto_interpret() if interpret is None else interpret
     return paged_verify_attention_pallas(q, k_pages, v_pages, block_tables,
                                          lengths, window=window,
                                          interpret=interpret)
@@ -107,8 +101,7 @@ def paged_verify_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
 @functools.partial(jax.jit, static_argnames=("c_blk", "f_blk", "interpret"))
 def moe_gmm(x, w_gate, w_up, w_down, *, c_blk: int = 128, f_blk: int = 128,
-            interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _auto_interpret() if interpret is None else interpret
+            interpret: bool = False) -> jax.Array:
     x_p, c0 = _pad_to(x, 1, min(c_blk, max(x.shape[1], 1)))
     wg_p, f0 = _pad_to(w_gate, 2, min(f_blk, max(w_gate.shape[2], 1)))
     wu_p, _ = _pad_to(w_up, 2, min(f_blk, max(w_up.shape[2], 1)))
@@ -159,12 +152,11 @@ def fetch_expert_ids(tile_expert: jax.Array, n_experts: int) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=("m_blk", "f_blk", "interpret"))
 def moe_gmm_ragged(rows, w_gate, w_up, w_down, tile_expert, *,
                    m_blk: int = 128, f_blk: int = 128,
-                   interpret: Optional[bool] = None) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """Ragged grouped matmul: rows (n_rows, d) sorted by expert with
     tile-aligned groups, tile_expert (n_rows/m_blk,) the per-tile owner
     (n_experts = sentinel). Pads F to the tile multiple; rows must already
     be m_blk-aligned (models.moe.ragged_dispatch pads them)."""
-    interpret = _auto_interpret() if interpret is None else interpret
     assert rows.shape[0] % m_blk == 0, (rows.shape, m_blk)
     n_experts = w_gate.shape[0]
     wg_p, f0 = _pad_to(w_gate, 2, min(f_blk, max(w_gate.shape[2], 1)))
@@ -176,20 +168,21 @@ def moe_gmm_ragged(rows, w_gate, w_up, w_down, tile_expert, *,
                                  interpret=interpret)
 
 
-def model_gmm_fn(cfg=None):
+def model_gmm_fn(cfg=None, *, interpret: bool = False):
     """Adapter matching models.moe.apply_moe's dense ``gmm_fn`` contract."""
     def fn(cfg_, p, buf):
-        return moe_gmm(buf, p["w_gate"], p["w_up"], p["w_down"])
+        return moe_gmm(buf, p["w_gate"], p["w_up"], p["w_down"],
+                       interpret=interpret)
     fn.ragged = False
     return fn
 
 
-def ragged_gmm_fn(cfg=None):
+def ragged_gmm_fn(cfg=None, *, interpret: bool = False):
     """Adapter matching models.moe.apply_moe's ragged ``gmm_fn`` contract
     (moe_dispatch="ragged"): receives the expert-sorted row buffer plus the
     per-tile expert metadata and runs the scalar-prefetch Pallas kernel."""
     def fn(cfg_, p, rows, tile_expert, m_blk):
         return moe_gmm_ragged(rows, p["w_gate"], p["w_up"], p["w_down"],
-                              tile_expert, m_blk=m_blk)
+                              tile_expert, m_blk=m_blk, interpret=interpret)
     fn.ragged = True
     return fn

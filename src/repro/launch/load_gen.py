@@ -10,8 +10,8 @@ replays), POST them to ``/v1/generate``, parse the SSE stream, honor 429
 from the same ServeConfig — the mode the CI smoke lane and the tests run.
 
 Verification (``--verify``, default in in-process mode): after the load
-run, a FRESH engine with identical params (``jax.random.PRNGKey(0)`` —
-engine init is deterministic) replays the same trace through the offline
+run, a FRESH engine over the same weights (``serve.model_and_params``
+initialises them once per process) replays the same trace through the offline
 ``ServingRuntime`` under the iteration clock, and every request's live
 token stream must be bit-identical to its offline twin.  Greedy token
 identity is scheduling-invariant (the PR-2/PR-6 invariant), so this holds
@@ -216,7 +216,7 @@ async def run_load(host: str, port: int, trace, n_clients: int,
 
 
 def offline_tokens(sc: ServeConfig, trace) -> List[List[int]]:
-    """The ground truth: a fresh identically-seeded engine replays the
+    """The ground truth: a fresh engine over the same weights replays the
     same trace through the offline runtime (iteration clock, no HTTP,
     no threads); returns per-trace-index token lists."""
     from repro.launch.serve import build_engine
@@ -328,9 +328,9 @@ def main() -> None:
                     help="write the JSON report here")
     args = ap.parse_args()
     sc = ServeConfig.from_args(args)
-    if not sc.simulate and not sc.smoke:
-        sc.smoke = True
-    sc.slots = min(sc.slots, 8)
+    if args.url is None:
+        from repro.launch.serve import setup_compile_cache
+        setup_compile_cache()
     if args.verify is None:
         args.verify = args.url is None
     sys.exit(asyncio.run(_amain(sc, args)))

@@ -1,6 +1,6 @@
-"""Production serving launcher: real-execution engine (smoke-sized models
-on CPU; the same engine code path runs under a device mesh on TPU) or the
-discrete-event simulator at full model scale.  Both run the SAME
+"""Production serving launcher: the real-execution engine on the model
+``--arch`` names (``--smoke`` swaps in its tiny same-family variant for CPU
+runs) or the discrete-event simulator at full model scale.  Both run the SAME
 ServingRuntime loop (serving/runtime.py): closed-loop drain by default,
 open-loop timed-trace replay with ``--open-loop`` (engine) or
 ``--simulate`` (always open-loop), optional per-token streaming via
@@ -13,9 +13,13 @@ configuration the benchmarks and the load generator consume, with
 ``to_json``/``from_json`` round-trips for recording exactly what ran.
 
 Usage:
-  # real engine, reduced model, layered prefill, closed loop:
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-moe-235b-a22b \
-      --smoke --scheduler layered --requests 8
+  # real engine, Qwen3-30B-A3B at published widths cut to one v5e chip:
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-30b-a3b-1chip \
+      --scheduler layered --requests 8 --slots 8 --max-len 2048
+
+  # real engine, reduced model on the CPU, layered prefill, closed loop:
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+      --arch qwen3-moe-235b-a22b --smoke --scheduler layered --requests 8
 
   # real engine, open-loop Poisson replay with streamed tokens:
   PYTHONPATH=src python -m repro.launch.serve --smoke --open-loop \
@@ -40,6 +44,8 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import math
+import os
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -107,12 +113,59 @@ def _print_faults(tag: str, fi: FaultInjector | None, requests) -> None:
           + (f"; shed {sheds}" if sheds else "; no requests shed"))
 
 
+# the checkout root (src/repro/launch/serve.py -> three levels up)
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is what JAX already uses and
+    nothing else is set here; otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache`` (the path is part of every entry's key, so a
+    directory that moved between runs would never hit).  Entry points call
+    this; importing the module never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+_WEIGHTS: dict = {}
+
+
+def model_and_params(sc: ServeConfig):
+    """The served model and its weights, initialised once per process by
+    one jitted ``model.init`` (seeded, so every engine of a process — and
+    of any other process — holds the same weights).  Every real-execution
+    path builds on this, so two engines of one model share one copy of the
+    weights, and only the most recent model's weights are held.  Raises
+    ``MemoryError`` with the byte counts when the weights and the KV slot
+    rows alone exceed the device's memory."""
+    cfg = get_smoke_config(sc.arch) if sc.smoke else get_config(sc.arch)
+    if cfg not in _WEIGHTS:
+        _WEIGHTS.clear()
+        model = DecoderModel(cfg)
+        init = jax.jit(model.init)
+        key = jax.random.PRNGKey(0)
+        need = (sum(a.size * a.dtype.itemsize for a in
+                    jax.tree_util.tree_leaves(jax.eval_shape(init, key)))
+                + cfg.kv_bytes_per_token() * sc.slots * sc.max_len)
+        dev = jax.devices()[0]
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        if limit is not None and need > limit:
+            raise MemoryError(
+                f"{cfg.name}: weights plus {sc.slots} x {sc.max_len} KV "
+                f"rows need {need} bytes; the {dev.device_kind} holds "
+                f"{limit}")
+        _WEIGHTS[cfg] = (model, init(key))
+    return _WEIGHTS[cfg]
+
+
 def build_engine(sc: ServeConfig) -> Engine:
     """The one engine constructor every real-execution mode shares
     (closed loop, open-loop replay, HTTP service, load_gen verification)."""
-    cfg = get_smoke_config(sc.arch) if sc.smoke else get_config(sc.arch)
-    model = DecoderModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = model_and_params(sc)
     sched = make_scheduler(sc.scheduler, model.n_blocks,
                            **sc.scheduler_kwargs())
     return Engine(model, params, sched, **sc.engine_kwargs())
@@ -132,9 +185,7 @@ def build_disagg_engines(sc: ServeConfig):
     """(prefill, decode) Engine pair sharing one model + params: the
     prefill pool runs the selected scheduler, the decode pool the
     internal decode-only scheduler (residents arrive via ``adopt``)."""
-    cfg = get_smoke_config(sc.arch) if sc.smoke else get_config(sc.arch)
-    model = DecoderModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = model_and_params(sc)
     sp = make_scheduler(sc.scheduler, model.n_blocks,
                         **sc.scheduler_kwargs())
     sd = make_scheduler("decode", model.n_blocks, **sc.scheduler_kwargs())
@@ -406,11 +457,7 @@ def main() -> None:
     if sc.simulate:
         serve_sim(sc)
         return
-    if not sc.smoke:
-        sc.smoke = True
-        print("[serve] full-scale real execution needs TPU; using "
-              "--smoke model (use --simulate for full-scale numbers)")
-    sc.slots = min(sc.slots, 8)
+    setup_compile_cache()
     if sc.http is not None:
         serve_http(sc)
     elif sc.disagg:

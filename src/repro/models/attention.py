@@ -19,7 +19,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import layers
@@ -389,7 +389,7 @@ def _sharded_masked_attention(plan, q, k, v, q_pos, kv_pos, kv_valid, *,
         body, mesh=mesh,
         in_specs=(P(ba, None, tp_axes, None), kv_spec, kv_spec,
                   P(ba, None), P(ba, None), P(ba, None)),
-        out_specs=P(ba, None, tp_axes, None), check_rep=False,
+        out_specs=P(ba, None, tp_axes, None), check_vma=False,
     )(q, k, v, q_pos, kv_pos, kv_valid)
 
 

@@ -293,11 +293,6 @@ class DecoderModel:
 
     # -- partial vertical execution (the layered-prefill primitive) -------------
 
-    def block_params(self, params, b: int):
-        s, r, p_idx = self.index_map[b]
-        return jax.tree_util.tree_map(
-            lambda a: a[r], params["segments"][s]["pattern"][p_idx])
-
     def run_blocks(self, params, x: Array, start: int, n: int, *,
                    positions: Array, offset: Optional[Array] = None,
                    cache: Optional[list] = None,
@@ -323,7 +318,13 @@ class DecoderModel:
         for b in range(start, start + n):
             s, r, p_idx = self.index_map[b]
             spec = self.specs[b]
-            bp = self.block_params(params, b)
+            # slice this block's weights only once the previous block has
+            # run: a loop over expert tiles (kernels.ref) needs its weights
+            # in a buffer of their own, and XLA would otherwise copy every
+            # block's slice up front (7 GB at Qwen3-30B-A3B widths, 6 blocks)
+            x, seg = jax.lax.optimization_barrier(
+                (x, params["segments"][s]["pattern"][p_idx]))
+            bp = jax.tree_util.tree_map(lambda a: a[r], seg)
             c = (jax.tree_util.tree_map(lambda a: a[r], cache[s][p_idx])
                  if cache is not None else None)
             x, nc, aux = blocks.apply_block(

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
 from repro.models.layers import _dense
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.partition import active_context
@@ -499,10 +499,10 @@ def _apply_moe_shard_map(cfg: ModelConfig, p, xf: Array,
                 fn = shard_map(lambda r, g_, u_, d_, xr, _:
                                body(r, g_, u_, d_, xr, None), mesh=mesh,
                                in_specs=in_specs, out_specs=out_specs,
-                               check_rep=False)
+                               check_vma=False)
             else:
                 fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+                               out_specs=out_specs, check_vma=False)
             v_arg = vflat if vflat is not None else jnp.ones((), bool)
             return fn(p["router"], p["w_gate"], p["w_up"], p["w_down"], xf,
                       v_arg)
@@ -546,10 +546,10 @@ def _apply_moe_shard_map(cfg: ModelConfig, p, xf: Array,
             fn = shard_map(lambda r, g_, u_, d_, xr, _:
                            body(r, g_, u_, d_, xr, None), mesh=mesh,
                            in_specs=in_specs, out_specs=out_specs,
-                           check_rep=False)
+                           check_vma=False)
         else:
             fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+                           out_specs=out_specs, check_vma=False)
         v_arg = vflat if vflat is not None else jnp.ones((), bool)
         return fn(p["router"], p["w_gate"], p["w_up"], p["w_down"], xf,
                   v_arg)
@@ -592,10 +592,10 @@ def _apply_moe_shard_map(cfg: ModelConfig, p, xf: Array,
         fn = shard_map(lambda r, g_, u_, d_, xr, _:
                        body(r, g_, u_, d_, xr, None), mesh=mesh,
                        in_specs=in_specs, out_specs=out_specs,
-                       check_rep=False)
+                       check_vma=False)
     else:
         fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
     v_arg = vflat if vflat is not None else jnp.ones((), bool)
     return fn(p["router"], p["w_gate"], p["w_up"], p["w_down"], xf, v_arg)
 

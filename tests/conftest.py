@@ -6,48 +6,12 @@ repro.launch.dryrun, per the brief).
 
 from __future__ import annotations
 
-import os
-
 import jax
 import pytest
 
 from repro.models.config import MLAConfig, ModelConfig, MoEConfig
 
 jax.config.update("jax_enable_x64", False)
-
-# -- pre-existing seed failures -------------------------------------------
-# tests/seed_xfails.txt is the single source of truth for the known-bad
-# node ids ("no worse than seed" bar): they run as xfail(strict=False), so
-# plain `pytest -x -q` agrees between local runs and CI with no deselect
-# flags — and an accidental fix shows up as XPASS instead of breaking.
-
-_XFAIL_FILE = os.path.join(os.path.dirname(__file__), "seed_xfails.txt")
-
-
-def _seed_xfail_prefixes():
-    try:
-        with open(_XFAIL_FILE) as f:
-            lines = (ln.strip() for ln in f)
-            return [ln for ln in lines if ln and not ln.startswith("#")]
-    except OSError:
-        return []
-
-
-def pytest_collection_modifyitems(config, items):
-    prefixes = _seed_xfail_prefixes()
-    if not prefixes:
-        return
-    marker = pytest.mark.xfail(
-        reason="pre-existing seed failure (tests/seed_xfails.txt)",
-        strict=False)
-    for item in items:
-        nodeid = item.nodeid.replace(os.sep, "/")
-        for p in prefixes:
-            # a bare prefix matches the whole function incl. parametrized
-            # variants (::name[...]), but not a longer name sharing it
-            if nodeid == p or nodeid.startswith(p + "["):
-                item.add_marker(marker)
-                break
 
 
 def tiny_dense(**kw) -> ModelConfig:

@@ -6,6 +6,8 @@ no-NaN. Decode-capable archs also run one cached decode step."""
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,3 +158,12 @@ def test_registry_covers_paper_models():
     assert (q.moe.n_experts, q.moe.top_k) == (128, 8)   # paper Table 3
     g = get_config("gpt-oss-20b")
     assert (g.moe.n_experts, g.moe.top_k) == (32, 4)
+
+
+def test_one_chip_config_cuts_only_depth():
+    """qwen3-30b-a3b-1chip keeps every published width; only the layer
+    count is cut to what one TPU v5e holds."""
+    full, one = get_config("qwen3-30b-a3b"), get_config("qwen3-30b-a3b-1chip")
+    assert 4 <= one.n_layers < full.n_layers
+    assert dataclasses.replace(one, name=full.name,
+                               n_layers=full.n_layers) == full

@@ -1,6 +1,7 @@
 """Per-kernel validation: sweep shapes/dtypes and assert_allclose against
 the pure-jnp oracles in kernels/ref.py. All kernels execute in Pallas
-interpret mode on CPU (the TPU lowering path is identical code)."""
+interpret mode on CPU (``interpret=True`` is always explicit; the TPU
+lowering of the same kernels is compiled in tests/test_tpu_compile.py)."""
 
 from __future__ import annotations
 
@@ -22,6 +23,14 @@ def _rand(key, shape, dtype):
 def _tol(dtype):
     return dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 \
         else dict(atol=2e-5, rtol=2e-5)
+
+
+def test_kernel_without_interpret_refuses_cpu():
+    """No silent interpret mode: off the TPU a kernel runs only when the
+    caller asks for the interpreter."""
+    q = jnp.zeros((1, 128, 4, 64))
+    with pytest.raises(Exception, match="interpret"):
+        ops.flash_attention(q, q, q)
 
 
 # ---------------------------------------------------------------- flash attn
@@ -144,8 +153,8 @@ def _ragged_block_tables(rng, b, page_size, n_pages, max_pages):
 def test_paged_decode_attention(b, h, hkv, hd, page, npages, maxp, dtype):
     ks = jax.random.split(jax.random.PRNGKey(b * hd + page), 3)
     q = _rand(ks[0], (b, h, hd), dtype)
-    kp = _rand(ks[1], (npages, page, hkv, hd), dtype)
-    vp = _rand(ks[2], (npages, page, hkv, hd), dtype)
+    kp = _rand(ks[1], (hkv, npages, page, hd), dtype)
+    vp = _rand(ks[2], (hkv, npages, page, hd), dtype)
     lengths, bt = _ragged_block_tables(
         np.random.default_rng(b * page), b, page, npages, maxp)
     got = ops.paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
@@ -158,8 +167,8 @@ def test_paged_decode_attention_windowed():
     b, h, hkv, hd, page, npages, maxp = 2, 4, 2, 64, 16, 16, 5
     ks = jax.random.split(jax.random.PRNGKey(11), 3)
     q = _rand(ks[0], (b, h, hd), jnp.float32)
-    kp = _rand(ks[1], (npages, page, hkv, hd), jnp.float32)
-    vp = _rand(ks[2], (npages, page, hkv, hd), jnp.float32)
+    kp = _rand(ks[1], (hkv, npages, page, hd), jnp.float32)
+    vp = _rand(ks[2], (hkv, npages, page, hd), jnp.float32)
     lengths, bt = _ragged_block_tables(
         np.random.default_rng(5), b, page, npages, maxp)
     got = ops.paged_decode_attention(q, kp, vp, bt, lengths, window=24,
@@ -191,12 +200,14 @@ def test_paged_matches_contiguous_decode_via_allocator_tables():
     v_slot = _rand(ks[2], (b, s_max, hkv, hd), jnp.float32)
     # physical placement: page j of request rid holds slot row tokens
     # [j*page, (j+1)*page) — exactly what the engine's scatter would do
-    kp = np.zeros((kv.n_pages, page, hkv, hd), np.float32)
+    kp = np.zeros((hkv, kv.n_pages, page, hd), np.float32)
     vp = np.zeros_like(kp)
     for rid in range(b):
         for j, pid in enumerate(kv.block_table(rid)):
-            kp[pid] = np.asarray(k_slot[rid, j * page:(j + 1) * page])
-            vp[pid] = np.asarray(v_slot[rid, j * page:(j + 1) * page])
+            kp[:, pid] = np.asarray(
+                k_slot[rid, j * page:(j + 1) * page]).transpose(1, 0, 2)
+            vp[:, pid] = np.asarray(
+                v_slot[rid, j * page:(j + 1) * page]).transpose(1, 0, 2)
     got = ops.paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
                                      jnp.asarray(bt),
                                      jnp.asarray(lengths), interpret=True)
@@ -237,8 +248,8 @@ def _verify_tables(rng, b, w, page_size, n_pages, max_pages):
 def test_paged_verify_attention(b, w, h, hkv, hd, page, npages, maxp, dtype):
     ks = jax.random.split(jax.random.PRNGKey(b * hd + w), 3)
     q = _rand(ks[0], (b, w, h, hd), dtype)
-    kp = _rand(ks[1], (npages, page, hkv, hd), dtype)
-    vp = _rand(ks[2], (npages, page, hkv, hd), dtype)
+    kp = _rand(ks[1], (hkv, npages, page, hd), dtype)
+    vp = _rand(ks[2], (hkv, npages, page, hd), dtype)
     lengths, bt = _verify_tables(
         np.random.default_rng(b * page + w), b, w, page, npages, maxp)
     got = ops.paged_verify_attention(q, kp, vp, bt, lengths, interpret=True)
@@ -254,8 +265,8 @@ def test_paged_verify_window_one_matches_decode():
     b, h, hkv, hd, page, npages, maxp = 3, 4, 2, 64, 16, 24, 6
     ks = jax.random.split(jax.random.PRNGKey(17), 3)
     q = _rand(ks[0], (b, h, hd), jnp.float32)
-    kp = _rand(ks[1], (npages, page, hkv, hd), jnp.float32)
-    vp = _rand(ks[2], (npages, page, hkv, hd), jnp.float32)
+    kp = _rand(ks[1], (hkv, npages, page, hd), jnp.float32)
+    vp = _rand(ks[2], (hkv, npages, page, hd), jnp.float32)
     lengths, bt = _ragged_block_tables(
         np.random.default_rng(9), b, page, npages, maxp)
     got = ops.paged_verify_attention(q[:, None], kp, vp, bt, lengths,
@@ -268,8 +279,8 @@ def test_paged_verify_attention_windowed():
     b, w, h, hkv, hd, page, npages, maxp = 2, 3, 4, 2, 64, 16, 16, 5
     ks = jax.random.split(jax.random.PRNGKey(23), 3)
     q = _rand(ks[0], (b, w, h, hd), jnp.float32)
-    kp = _rand(ks[1], (npages, page, hkv, hd), jnp.float32)
-    vp = _rand(ks[2], (npages, page, hkv, hd), jnp.float32)
+    kp = _rand(ks[1], (hkv, npages, page, hd), jnp.float32)
+    vp = _rand(ks[2], (hkv, npages, page, hd), jnp.float32)
     lengths, bt = _verify_tables(
         np.random.default_rng(6), b, w, page, npages, maxp)
     got = ops.paged_verify_attention(q, kp, vp, bt, lengths, window=24,
@@ -403,8 +414,8 @@ def test_model_forward_with_pallas_gmm_matches_ref():
     params = model.init(jax.random.PRNGKey(0))
     tokens = jnp.arange(1, 33, dtype=jnp.int32).reshape(2, 16)
     ref_logits, _, _ = model.forward(params, tokens)
-    got_logits, _, _ = model.forward(params, tokens,
-                                     gmm_fn=ops.model_gmm_fn(cfg))
+    got_logits, _, _ = model.forward(
+        params, tokens, gmm_fn=ops.model_gmm_fn(cfg, interpret=True))
     np.testing.assert_allclose(np.asarray(got_logits), np.asarray(ref_logits),
                                atol=1e-4, rtol=1e-4)
 
@@ -423,7 +434,8 @@ def test_model_forward_with_ragged_pallas_gmm_matches_ref():
     tokens = jnp.arange(1, 33, dtype=jnp.int32).reshape(2, 16)
     ref_logits, _, ref_aux = model.forward(params, tokens, dropless=True)
     got_logits, _, got_aux = model.forward(params, tokens,
-                                           gmm_fn=ops.ragged_gmm_fn(cfg),
+                                           gmm_fn=ops.ragged_gmm_fn(
+                                               cfg, interpret=True),
                                            moe_dispatch="ragged")
     np.testing.assert_allclose(np.asarray(got_logits), np.asarray(ref_logits),
                                atol=1e-4, rtol=1e-4)
