@@ -272,6 +272,11 @@ class IterationPlan:
     # reservation is released.  Requests absent from this dict decode one
     # token exactly as before — an empty dict is the non-speculative plan.
     verify_len: Dict[int, int] = field(default_factory=dict)
+    # perf_counter_ns at which the serving loop began planning this
+    # iteration (its ``scheduler.plan`` span); None for a plan made outside
+    # ServingRuntime.run.  A request's ``request.prefill`` span starts at
+    # the plan that admitted it.
+    planned_ns: Optional[int] = field(default=None, compare=False)
 
     @property
     def empty(self) -> bool:

@@ -252,14 +252,15 @@ def _write_cache(buf: Array, new: Array, offset: Array,
     S ~ prompt_len) would silently slide the write backwards and overwrite
     live KV below ``offset``.  The scatter stays O(B*S): one index per new
     token, masked tokens routed out of range."""
-    new = new.astype(buf.dtype)
-    b, s = new.shape[:2]
-    s_max = buf.shape[1]
-    pos = offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-    if tok_ok is not None:
-        pos = jnp.where(tok_ok, pos, s_max)        # masked -> OOB -> dropped
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    return buf.at[rows, pos].set(new, mode="drop")
+    with jax.named_scope("kv_write"):
+        new = new.astype(buf.dtype)
+        b, s = new.shape[:2]
+        s_max = buf.shape[1]
+        pos = offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        if tok_ok is not None:
+            pos = jnp.where(tok_ok, pos, s_max)    # masked -> OOB -> dropped
+        rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+        return buf.at[rows, pos].set(new, mode="drop")
 
 
 # ---------------------------------------------------------------------------

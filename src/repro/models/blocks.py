@@ -68,9 +68,10 @@ def apply_block(cfg: ModelConfig, spec: BlockSpec, p, x: Array, *,
     across block kinds so heterogeneous stacks scan cleanly."""
     h = layers.apply_norm(cfg, p["ln1"], x)
     if spec.is_attention():
-        out, new_cache = attention.apply_mixer_attn(
-            cfg, spec, p["attn"], h, positions=positions, offset=offset,
-            cache=cache, valid=valid, positions3=positions3)
+        with jax.named_scope("attention"):
+            out, new_cache = attention.apply_mixer_attn(
+                cfg, spec, p["attn"], h, positions=positions, offset=offset,
+                cache=cache, valid=valid, positions3=positions3)
         x = x + out
         if spec.cross_attn:
             hx = layers.apply_norm(cfg, p["attn"]["x_norm"], x)

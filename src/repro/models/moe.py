@@ -349,17 +349,19 @@ def apply_moe(cfg: ModelConfig, p, x: Array, *,
         out, counts, dropped, pbar = _apply_moe_shard_map(
             cfg, p, xf, vflat, gmm_fn, dropless, plan, moe_dispatch)
     else:
-        idx, w, probs = route(cfg, p, xf)
-        if vflat is not None:
-            # invalid tokens route out-of-bounds => dropped from dispatch
-            idx = jnp.where(vflat[:, None], idx, e.n_experts)
-        if moe_dispatch == "ragged":
-            out, counts, dropped = _dispatch_gmm_combine_ragged(
-                cfg, p, xf, idx, w, e.n_experts, gmm_fn)
-        else:
-            cap = t if dropless else capacity(cfg, t)
-            out, counts, dropped = _dispatch_gmm_combine(
-                cfg, p, xf, idx, w, cap, e.n_experts, gmm_fn)
+        with jax.named_scope("moe.route"):
+            idx, w, probs = route(cfg, p, xf)
+            if vflat is not None:
+                # invalid tokens route out-of-bounds => dropped from dispatch
+                idx = jnp.where(vflat[:, None], idx, e.n_experts)
+        with jax.named_scope("moe.experts"):
+            if moe_dispatch == "ragged":
+                out, counts, dropped = _dispatch_gmm_combine_ragged(
+                    cfg, p, xf, idx, w, e.n_experts, gmm_fn)
+            else:
+                cap = t if dropless else capacity(cfg, t)
+                out, counts, dropped = _dispatch_gmm_combine(
+                    cfg, p, xf, idx, w, cap, e.n_experts, gmm_fn)
         pbar = jnp.mean(probs, axis=0)
 
     if e.n_shared_experts:

@@ -43,8 +43,7 @@ Hot-path contract (DESIGN.md §Engine hot path):
 
 from __future__ import annotations
 
-import functools
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -57,6 +56,7 @@ from repro.core.plan import (IterationPlan, PrefillSlice, Request,
 from repro.kernels.ops import gather_slot_rows, scatter_slot_rows
 from repro.models.config import dtype_bytes
 from repro.models.model import DecoderModel
+from repro.serving import spans
 from repro.serving.kvcache import PagedKVAllocator
 from repro.serving.runtime import (EngineExecutor, RunResult, ServingRuntime,
                                    TokenEvent, timestamp_events)
@@ -70,6 +70,10 @@ Array = jax.Array
 # on the triple alone, mixed-shape traces used to retrace INSIDE an entry
 # and grow live executables past the bound unobserved).
 PREFILL_CACHE_SIZE = 32
+
+# rows of ``Engine.iter_log`` kept (the newest): a long-running server must
+# not grow it without bound
+ITER_LOG_SIZE = 2 ** 16
 
 
 def _bucket(n: int, minimum: int = 16, cap: Optional[int] = None) -> int:
@@ -280,7 +284,11 @@ class Engine:
         self.n_swapped_out = 0
         self.n_swapped_in = 0
         self.expert_load_bytes = 0
-        self.iter_log: List[dict] = []
+        # one row per executed iteration, the newest ITER_LOG_SIZE kept
+        self.iter_log: deque = deque(maxlen=ITER_LOG_SIZE)
+        # req -> (ns its admitting plan began, iteration): the start of its
+        # ``request.prefill`` span, until its first token
+        self._prefill_from: Dict[int, Tuple[int, int]] = {}
         bytes_per_el = dtype_bytes(self.cfg.param_dtype)
         self._expert_bytes = self.cfg.expert_bytes(bytes_per_el)
         # dispatch accounting (benchmarks/engine_iter_bench.py and the
@@ -292,11 +300,8 @@ class Engine:
         self.n_prefill_dispatches = 0
         self.n_prefill_compiles = 0
         # prefix-cache accounting: restores = admissions that seeded their
-        # slot row from a cached prefix (the allocator counts hits/tokens).
-        # Hits are counted at plan-time reserve, so iter_log attribution
-        # tracks the allocator counters seen at the last append.
+        # slot row from a cached prefix (the allocator counts hits/tokens)
         self.n_prefix_restores = 0
-        self._prefix_seen = (0, 0)          # (n_prefix_hits, n_prefix_tokens)
         # speculative-decode accounting: verify/draft executables live in
         # the SAME bounded LRU as prefill executables (a growing family of
         # k buckets must not grow live executables past the bound)
@@ -424,22 +429,40 @@ class Engine:
             tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return x, cache, loads, tokens
 
+    def _executable(self, key: tuple, build, counter: str):
+        """The bounded LRU's entry for ``key``.  On a miss ``build()`` makes
+        the jitted function, the engine counter ``counter`` counts it, and
+        its first call, which traces and compiles it, runs inside an
+        ``engine.build`` span."""
+        cache = self._jit_prefill
+        if key in cache:
+            cache.move_to_end(key)
+            return cache[key]
+        fn = build()
+
+        def first_call(*args):
+            with spans.span("engine.build", key=key):
+                out = fn(*args)
+            if key in cache:
+                cache[key] = fn
+            return out
+        cache[key] = first_call
+        setattr(self, counter, getattr(self, counter) + 1)
+        while len(cache) > PREFILL_CACHE_SIZE:
+            cache.popitem(last=False)
+        return first_call
+
     def _get_prefill_fn(self, start: int, n: int, emit: bool,
                         b: int, p: int):
         """One executable per (block_start, n_blocks, emit, B_bucket,
         P_bucket).  The shape buckets are part of the LRU key so the
         PREFILL_CACHE_SIZE bound counts executables, not trace families."""
-        key = (start, n, emit, b, p)
-        if key in self._jit_prefill:
-            self._jit_prefill.move_to_end(key)
-        else:
-            self._jit_prefill[key] = jax.jit(
-                functools.partial(self._prefill_impl, start, n, emit),
-                donate_argnums=(1,))
-            self.n_prefill_compiles += 1
-            while len(self._jit_prefill) > PREFILL_CACHE_SIZE:
-                self._jit_prefill.popitem(last=False)
-        return self._jit_prefill[key]
+        def build():
+            def prefill_group(*args):
+                return self._prefill_impl(start, n, emit, *args)
+            return jax.jit(prefill_group, donate_argnums=(1,))
+        return self._executable((start, n, emit, b, p), build,
+                                "n_prefill_compiles")
 
     def _get_embed_fn(self):
         if "f" not in self._jit_embed:
@@ -478,16 +501,10 @@ class Engine:
         keys — bucketed k means adaptive speculation lengths reuse a small
         executable family, and the shared PREFILL_CACHE_SIZE bound counts
         them like any other live executable."""
-        key = ("verify", b, p)
-        if key in self._jit_prefill:
-            self._jit_prefill.move_to_end(key)
-        else:
-            self._jit_prefill[key] = jax.jit(self._verify_impl,
-                                             donate_argnums=(1,))
-            self.n_verify_compiles += 1
-            while len(self._jit_prefill) > PREFILL_CACHE_SIZE:
-                self._jit_prefill.popitem(last=False)
-        return self._jit_prefill[key]
+        return self._executable(
+            ("verify", b, p),
+            lambda: jax.jit(self._verify_impl, donate_argnums=(1,)),
+            "n_verify_compiles")
 
     def _draft_impl(self, k, params, tokens, lengths):
         """Greedy k-step extension by the STATELESS draft model, one jitted
@@ -513,16 +530,12 @@ class Engine:
 
     def _get_draft_fn(self, k: int, b: int, p: int):
         """Draft executables share the bounded prefill LRU too."""
-        key = ("draft", k, b, p)
-        if key in self._jit_prefill:
-            self._jit_prefill.move_to_end(key)
-        else:
-            self._jit_prefill[key] = jax.jit(
-                functools.partial(self._draft_impl, k))
-            self.n_verify_compiles += 1
-            while len(self._jit_prefill) > PREFILL_CACHE_SIZE:
-                self._jit_prefill.popitem(last=False)
-        return self._jit_prefill[key]
+        def build():
+            def draft(*args):
+                return self._draft_impl(k, *args)
+            return jax.jit(draft)
+        return self._executable(("draft", k, b, p), build,
+                                "n_verify_compiles")
 
     # -------------------------------------------------------------- stepping
 
@@ -550,8 +563,22 @@ class Engine:
         — and all bookkeeping (offsets, EOS, token events, expert union)
         runs on the fetched numpy values."""
         self._step_events: List[TokenEvent] = []
+        prefill_tokens = sum(sl.n_tokens for sl in plan.prefill)
+        with spans.span("engine.step", iteration=self.iteration,
+                        n_decode=len(plan.decode_ids),
+                        prefill_tokens=prefill_tokens) as step:
+            first = self._execute(plan, step)
+        for rid in first:
+            t0_ns, it0 = self._prefill_from.pop(rid)
+            spans.record("request.prefill", t0_ns, step.end_ns, req_id=rid,
+                         n_steps=step.attrs["iteration"] - it0 + 1)
+        return self._step_events
+
+    def _execute(self, plan: IterationPlan,
+                 step: "spans.span") -> Tuple[int, ...]:
+        """``execute_plan``'s body, inside its ``engine.step`` span; returns
+        the requests whose first token this step emitted."""
         dispatches0 = self.n_dispatches
-        prefix_hits0, prefix_toks0 = self._prefix_seen
         block_expert_union = np.zeros(
             (self.model.n_blocks, max(self.cfg.moe.n_experts, 1)), bool)
 
@@ -559,115 +586,122 @@ class Engine:
         # released before this iteration's swap-ins/admissions reuse them.
         # Swap-out rows are snapshotted as device arrays (immutable — later
         # writes build new buffers) and join the end-of-iteration fetch.
-        for rid in plan.preempted_ids:
-            self._preempt(rid)
-        swap_pending = [self._swap_out(rid) for rid in plan.swapped_out_ids]
+        with spans.span("engine.admit"):
+            for rid in plan.preempted_ids:
+                self._preempt(rid)
+            swap_pending = [self._swap_out(rid)
+                            for rid in plan.swapped_out_ids]
+            for rid in plan.swapped_in_ids:
+                self._swap_in(rid)
+            for rid in plan.admitted_ids:
+                self._admit(rid)
+                if not self.outputs[rid]:      # not a recompute epoch
+                    self._prefill_from.setdefault(
+                        rid, (plan.planned_ns or step.start_ns,
+                              self.iteration))
 
-        for rid in plan.swapped_in_ids:
-            self._swap_in(rid)
-        for rid in plan.admitted_ids:
-            self._admit(rid)
+        with spans.span("engine.launch") as launch:
+            groups = self._pack_slices(plan.prefill)
+            launch.attrs["n_groups"] = step.attrs["n_groups"] = len(groups)
+            launched, staged = [], []
+            for g in groups:
+                launched.append(self._launch_prefill_group(*g))
+                if self.handoff_staging:
+                    # group-granular streaming: a slice whose token range
+                    # ends at the prompt completes its blocks' KV this
+                    # iteration — slice those rows NOW (before a later
+                    # donated call retires this cache buffer); values join
+                    # the single fetch below
+                    for sl in g[3]:
+                        if sl.token_end \
+                                == self.requests[sl.req_id].prompt_len:
+                            staged.append(
+                                (sl.req_id, sl.block_start, sl.block_end,
+                                 self._slice_block_rows(sl.req_id,
+                                                        sl.block_start,
+                                                        sl.block_end)))
 
-        groups = self._pack_slices(plan.prefill)
-        launched, staged = [], []
-        for g in groups:
-            launched.append(self._launch_prefill_group(*g))
-            if self.handoff_staging:
-                # group-granular streaming: a slice whose token range ends
-                # at the prompt completes its blocks' KV this iteration —
-                # slice those rows NOW (before a later donated call retires
-                # this cache buffer); values join the single fetch below
-                for sl in g[3]:
-                    if sl.token_end == self.requests[sl.req_id].prompt_len:
-                        staged.append(
-                            (sl.req_id, sl.block_start, sl.block_end,
-                             self._slice_block_rows(sl.req_id,
-                                                    sl.block_start,
-                                                    sl.block_end)))
-        prefill_tokens = sum(sl.n_tokens for sl in plan.prefill)
+            # speculative verify-k: draft + verify are LAUNCHED here (device
+            # arrays only); rows the drafter skipped fall through to the
+            # plain decode step below
+            spec_rows, spec_skipped, spec_fetch = [], [], None
+            if plan.verify_len and self.spec_mode != "off":
+                spec_rows, spec_skipped, spec_fetch = \
+                    self._launch_verify(plan)
+            spec_rids = {rid for rid, _, _, _, _ in spec_rows}
 
-        # speculative verify-k: draft + verify are LAUNCHED here (device
-        # arrays only); rows the drafter skipped fall through to the plain
-        # decode step below
-        spec_rows, spec_skipped, spec_fetch = [], [], None
-        if plan.verify_len and self.spec_mode != "off":
-            spec_rows, spec_skipped, spec_fetch = self._launch_verify(plan)
-        spec_rids = {rid for rid, _, _, _, _ in spec_rows}
-
-        decode_slot_req = decode_out = None
-        plain_ids = [rid for rid in plan.decode_ids if rid not in spec_rids]
-        if plain_ids:
-            decode_slot_req, decode_out = self._launch_decode(plain_ids)
+            decode_slot_req = decode_out = None
+            plain_ids = [rid for rid in plan.decode_ids
+                         if rid not in spec_rids]
+            if plain_ids:
+                decode_slot_req, decode_out = self._launch_decode(plain_ids)
 
         # ---- the ONE host sync per iteration ----
         if launched or decode_out is not None or swap_pending \
                 or spec_fetch is not None or staged:
-            launched, decode_out, spec_fetch, swap_rows, staged_rows = \
-                jax.device_get(
-                    (launched, decode_out, spec_fetch,
-                     [row for _, row in swap_pending],
-                     [rows for *_, rows in staged]))
-            for (rid, _), row in zip(swap_pending, swap_rows):
-                self.host_kv[rid] = (row,) + self.host_kv[rid][1:]
-            for (rid, b0, b1, _), rows in zip(staged, staged_rows):
-                self._handoff_chunks.setdefault(rid, []).append(
-                    (b0, b1, rows))
-                self.handoff_bytes += sum(
-                    a.nbytes for a in jax.tree_util.tree_leaves(rows))
+            with spans.span("engine.fetch"):
+                launched, decode_out, spec_fetch, swap_rows, staged_rows = \
+                    jax.device_get(
+                        (launched, decode_out, spec_fetch,
+                         [row for _, row in swap_pending],
+                         [rows for *_, rows in staged]))
 
-        for (start, end, emit, slices), (loads, toks) in zip(groups,
-                                                             launched):
-            block_expert_union[start:end] |= loads
-            for i, sl in enumerate(slices):
-                self._finish_prefill_slice(sl, int(toks[i]))
-        n_verify_tokens = n_spec_accepted = 0
-        self.last_verify_executed = {}
-        if spec_fetch is not None:
-            loads, toks, props = spec_fetch
-            block_expert_union |= loads
-            n_verify_tokens, n_spec_accepted = self._finish_verify(
-                spec_rows, toks, props)
-        if decode_out is not None:
-            next_tok, loads = decode_out
-            block_expert_union |= loads
-            for slot, rid in decode_slot_req.items():
-                tok = int(next_tok[slot])
-                self.offsets[slot] += 1
-                self.last_tok[slot] = tok
-                self._record_token(rid, tok, first=False)
-                self._maybe_finish(rid, tok)
-        for rid in spec_skipped:
-            # a 0-proposal commit releases the scheduler's page pre-charge
-            self.last_verify_executed[rid] = 0
-            self.scheduler.commit_speculation(rid, proposed=0, accepted=0,
-                                              extra=0)
+        with spans.span("engine.commit"):
+            if swap_pending or staged:
+                for (rid, _), row in zip(swap_pending, swap_rows):
+                    self.host_kv[rid] = (row,) + self.host_kv[rid][1:]
+                for (rid, b0, b1, _), rows in zip(staged, staged_rows):
+                    self._handoff_chunks.setdefault(rid, []).append(
+                        (b0, b1, rows))
+                    self.handoff_bytes += sum(
+                        a.nbytes for a in jax.tree_util.tree_leaves(rows))
 
-        if self.cfg.moe.enabled:
-            loaded = int(block_expert_union.sum())
-            self.expert_load_bytes += loaded * self._expert_bytes
-        self.iter_log.append({
-            "iteration": self.iteration,
-            "n_decode": len(plan.decode_ids),
-            "prefill_tokens": prefill_tokens,
-            "expert_load_bytes": (int(block_expert_union.sum())
-                                  * self._expert_bytes),
-            "pages_in_use": self.alloc.pages_in_use(),
-            "host_pages_in_use": self.alloc.host_pages_in_use(),
-            "n_preempted": len(plan.preempted_ids),
-            "n_swapped_out": len(plan.swapped_out_ids),
-            "n_swapped_in": len(plan.swapped_in_ids),
-            "n_dispatches": self.n_dispatches - dispatches0,
-            "n_verify_tokens": n_verify_tokens,
-            "n_spec_accepted": n_spec_accepted,
-            "n_spec_rows": len(spec_rows),
-            "n_prefix_hits": self.alloc.n_prefix_hits - prefix_hits0,
-            "prefix_cached_tokens": (self.alloc.n_prefix_tokens
-                                     - prefix_toks0),
-        })
-        self._prefix_seen = (self.alloc.n_prefix_hits,
-                             self.alloc.n_prefix_tokens)
-        self.iteration += 1
-        return self._step_events
+            for (start, end, emit, slices), (loads, toks) in zip(groups,
+                                                                 launched):
+                block_expert_union[start:end] |= loads
+                for i, sl in enumerate(slices):
+                    self._finish_prefill_slice(sl, int(toks[i]))
+            self.last_verify_executed = {}
+            if spec_fetch is not None:
+                loads, toks, props = spec_fetch
+                block_expert_union |= loads
+                self._finish_verify(spec_rows, toks, props)
+            if decode_out is not None:
+                next_tok, loads = decode_out
+                block_expert_union |= loads
+                for slot, rid in decode_slot_req.items():
+                    tok = int(next_tok[slot])
+                    self.offsets[slot] += 1
+                    self.last_tok[slot] = tok
+                    self._record_token(rid, tok, first=False)
+                    self._maybe_finish(rid, tok)
+            for rid in spec_skipped:
+                # a 0-proposal commit releases the scheduler's page
+                # pre-charge
+                self.last_verify_executed[rid] = 0
+                self.scheduler.commit_speculation(rid, proposed=0,
+                                                  accepted=0, extra=0)
+
+            loaded = int(block_expert_union.sum()) * self._expert_bytes
+            if self.cfg.moe.enabled:
+                self.expert_load_bytes += loaded
+            self.iter_log.append({
+                "iteration": self.iteration,
+                "n_decode": len(plan.decode_ids),
+                "prefill_tokens": step.attrs["prefill_tokens"],
+                "expert_load_bytes": loaded,
+                "pages_in_use": self.alloc.pages_in_use(),
+                "host_pages_in_use": self.alloc.host_pages_in_use(),
+                "n_preempted": len(plan.preempted_ids),
+                "n_swapped_out": len(plan.swapped_out_ids),
+                "n_swapped_in": len(plan.swapped_in_ids),
+                "n_dispatches": self.n_dispatches - dispatches0,
+            })
+            first = tuple(ev.req_id for ev in self._step_events
+                          if ev.first and ev.req_id in self._prefill_from)
+            step.attrs["first"] = first
+            self.iteration += 1
+        return first
 
     # ------------------------------------------------ disaggregated handoff
 
@@ -770,6 +804,7 @@ class Engine:
         self.host_kv.pop(rid, None)
         self.stash.pop(rid, None)
         self._handoff_chunks.pop(rid, None)
+        self._prefill_from.pop(rid, None)
 
     # -------------------------------------------------------------- helpers
 
@@ -1094,12 +1129,11 @@ class Engine:
         self.n_verify_dispatches += 1
         return rows, skipped, (loads, toks, props_dev)
 
-    def _finish_verify(self, rows, toks, props) -> Tuple[int, int]:
+    def _finish_verify(self, rows, toks, props) -> None:
         """Host bookkeeping for the fetched verify grid: accept the
         matching draft prefix, emit accepted drafts plus the target's own
         next token, advance the committed offset (the rollback — stale KV
         past it is dead), and feed acceptance back to the scheduler."""
-        n_proposed = n_accepted = 0
         for i, (rid, slot, off, k_eff, prop) in enumerate(rows):
             if prop is None:
                 prop = np.asarray(props[i, :k_eff])
@@ -1115,8 +1149,6 @@ class Engine:
             self.last_tok[slot] = emitted[-1]
             for t in emitted:
                 self._record_token(rid, t, first=False)
-            n_proposed += k_eff
-            n_accepted += a
             self.n_spec_proposed += k_eff
             self.n_spec_accepted += a
             self.last_verify_executed[rid] = k_eff
@@ -1124,7 +1156,6 @@ class Engine:
                 rid, proposed=k_eff, accepted=a, extra=len(emitted) - 1,
                 committed_len=int(self.offsets[slot]))
             self._maybe_finish(rid, emitted[-1])
-        return n_proposed, n_accepted
 
     def _launch_decode(self, decode_ids: List[int]):
         """Launch the full-pool decode step; returns the slot→request map

@@ -44,6 +44,7 @@ import numpy as np
 
 from repro.core.base import fold_for_recompute
 from repro.core.plan import IterationPlan, Request, RequestState, SubmitSpec
+from repro.serving import spans
 from repro.serving.faults import (FAULT_KINDS, DegradationLadder,
                                   ExecutorCrash, FaultInjector)
 
@@ -577,18 +578,24 @@ class ServingRuntime(_Supervised):
         i_arr = 0
         t = float(x.initial_clock())
 
+        def submit(spec, now: float) -> Request:
+            with spans.span("runtime.inject") as sp:
+                req = x.submit(spec, now)
+                sp.attrs.update(req_id=req.req_id, due=req.arrival_time)
+            return req
+
         def inject(now: float) -> None:
             nonlocal i_arr
             while i_arr < len(pending) \
                     and pending[i_arr].arrival_time <= now:
                 tr = pending[i_arr]
                 spec = tr.to_spec() if hasattr(tr, "to_spec") else tr
-                res.requests.append(x.submit(spec, now))
+                res.requests.append(submit(spec, now))
                 i_arr += 1
             if feed is not None:
                 for ticket in feed.drain():
                     try:
-                        req = x.submit(ticket.spec, now)
+                        req = submit(ticket.spec, now)
                     except Exception as e:     # bad spec: report, keep going
                         ticket._fail(e)
                         continue
@@ -598,61 +605,77 @@ class ServingRuntime(_Supervised):
         def live() -> bool:
             return feed is not None and not feed.exhausted
 
-        while i_arr < len(pending) or sched.has_work() or live():
-            inject(t)
-            if not sched.has_work():
-                if live():
-                    # live idle: block on the feed (bounded so wall clocks
-                    # stay responsive to close/shutdown), then re-read the
-                    # executor clock — arrivals are stamped at real idle
-                    # time, not at the last iteration's end
-                    feed.wait(idle_poll)
-                    t = max(t, x.poll_clock(t))
-                    continue
-                if i_arr >= len(pending):
-                    break          # feed closed + drained, nothing pending
-                # open-loop idle: fast-forward (or, on a wall clock, sleep)
-                # to the next arrival instead of raising "did not drain"
-                nxt = pending[i_arr].arrival_time
-                t = nxt if self.clock == "iteration" else x.idle(t, nxt)
+        with spans.span("runtime.run", clock=self.clock):
+            while i_arr < len(pending) or sched.has_work() or live():
                 inject(t)
-            if res.n_iterations >= max_iterations:
-                raise RuntimeError(diagnose_stall(
-                    f"did not drain within {max_iterations} iterations; "
-                    "scheduler stuck?", [("pool", sched)],
-                    pending=len(pending) - i_arr))
-            if self._supervise(sched, x, res, t, res.n_iterations):
-                continue       # supervision consumed all resident work
-            plan = sched.next_plan(now=t)
-            self._fail_swap_dma(sched, plan, res.n_iterations)
-            if self.record_plans:
-                self.plans.append(plan)
-            res.n_preemptions += len(plan.preempted_ids)
-            res.recompute_tokens += sum(
-                sched.requests[rid].prompt_len
-                for rid in plan.preempted_ids)
-            res.n_swap_outs += len(plan.swapped_out_ids)
-            res.n_swap_ins += len(plan.swapped_in_ids)
-            if plan.empty:
-                if i_arr < len(pending):
-                    # nothing runnable yet — fast-forward to the arrival
-                    # that will create work (t never moves backwards)
-                    t = max(t, pending[i_arr].arrival_time)
-                    continue
-                # no runnable work, no future arrivals: advancing neither
-                # t nor the iteration count would spin forever
-                raise RuntimeError(diagnose_stall(
-                    f"scheduler {sched.name!r} made no progress at t={t}: "
-                    "no pending arrivals and the next plan is empty",
-                    [("pool", sched)]))
-            outcome = x.execute(plan, t)
-            res.n_iterations += 1
-            res.n_dispatches += outcome.n_dispatches
-            res.decode_batch_sizes.append(len(plan.decode_ids))
-            t_end = t + (1.0 if self.clock == "iteration"
-                         else outcome.duration)
-            timestamp_events(sched, outcome.events, t_end, self.on_token)
-            t = t_end
+                if not sched.has_work():
+                    if live():
+                        # live idle: block on the feed (bounded so wall
+                        # clocks stay responsive to close/shutdown), then
+                        # re-read the executor clock — arrivals are stamped
+                        # at real idle time, not at the last iteration's end
+                        with spans.span("runtime.idle"):
+                            feed.wait(idle_poll)
+                        t = max(t, x.poll_clock(t))
+                        continue
+                    if i_arr >= len(pending):
+                        break      # feed closed + drained, nothing pending
+                    # open-loop idle: fast-forward (or, on a wall clock,
+                    # sleep) to the next arrival instead of raising "did
+                    # not drain"
+                    nxt = pending[i_arr].arrival_time
+                    if self.clock == "iteration":
+                        t = nxt
+                    else:
+                        with spans.span("runtime.idle"):
+                            t = x.idle(t, nxt)
+                    inject(t)
+                if res.n_iterations >= max_iterations:
+                    raise RuntimeError(diagnose_stall(
+                        f"did not drain within {max_iterations} iterations; "
+                        "scheduler stuck?", [("pool", sched)],
+                        pending=len(pending) - i_arr))
+                if self._supervise(sched, x, res, t, res.n_iterations):
+                    continue   # supervision consumed all resident work
+                # plan at the executor's present: on a wall clock an
+                # admission is stamped when the plan is made, not when the
+                # previous step ended (injection and supervision ran since)
+                t = max(t, x.poll_clock(t))
+                with spans.span("scheduler.plan") as sp:
+                    plan = sched.next_plan(now=t)
+                    plan.planned_ns = sp.start_ns
+                    sp.attrs.update(admitted=tuple(plan.admitted_ids),
+                                    n_decode=len(plan.decode_ids),
+                                    n_prefill=len(plan.prefill))
+                self._fail_swap_dma(sched, plan, res.n_iterations)
+                if self.record_plans:
+                    self.plans.append(plan)
+                res.n_preemptions += len(plan.preempted_ids)
+                res.recompute_tokens += sum(
+                    sched.requests[rid].prompt_len
+                    for rid in plan.preempted_ids)
+                res.n_swap_outs += len(plan.swapped_out_ids)
+                res.n_swap_ins += len(plan.swapped_in_ids)
+                if plan.empty:
+                    if i_arr < len(pending):
+                        # nothing runnable yet — fast-forward to the arrival
+                        # that will create work (t never moves backwards)
+                        t = max(t, pending[i_arr].arrival_time)
+                        continue
+                    # no runnable work, no future arrivals: advancing
+                    # neither t nor the iteration count would spin forever
+                    raise RuntimeError(diagnose_stall(
+                        f"scheduler {sched.name!r} made no progress at "
+                        f"t={t}: no pending arrivals and the next plan is "
+                        "empty", [("pool", sched)]))
+                outcome = x.execute(plan, t)
+                res.n_iterations += 1
+                res.n_dispatches += outcome.n_dispatches
+                res.decode_batch_sizes.append(len(plan.decode_ids))
+                t_end = t + (1.0 if self.clock == "iteration"
+                             else outcome.duration)
+                timestamp_events(sched, outcome.events, t_end, self.on_token)
+                t = t_end
 
         if self.faults is not None:
             self.faults.release_pressure(None)   # zero-leak: no phantom

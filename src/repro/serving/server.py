@@ -404,6 +404,9 @@ class ServingServer:
             "engine_dispatches_total": float(self.engine.n_dispatches),
             "engine_preempted_total": float(self.engine.n_preempted),
             "engine_swapped_out_total": float(self.engine.n_swapped_out),
+            "engine_executables_built_total": float(
+                self.engine.n_prefill_compiles
+                + self.engine.n_verify_compiles),
             "shed_streams_total": float(self.n_shed_streams),
         }
         counters.update(fault_counters(**self.runtime.fault_stats()))
