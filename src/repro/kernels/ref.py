@@ -110,10 +110,16 @@ def moe_gmm_ref(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 
 def moe_gmm_ragged_ref(rows: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                        w_down: jax.Array, tile_expert: jax.Array,
-                       m_blk: int) -> jax.Array:
+                       m_blk: int, layer=None) -> jax.Array:
     """Oracle for the ragged grouped matmul: per row-tile, apply the fused
     SwiGLU FFN of the tile's owning expert; sentinel tiles
     (tile_expert == E) produce zero rows. rows: (n_rows, d) -> (n_rows, d).
+
+    Weights are (E, d, F) / (E, F, d), or with ``layer`` (a static or
+    traced index) a layer stack (reps, E, d, F) / (reps, E, F, d) read at
+    ``[layer, expert]`` inside the loop.  A loop's operand lives in a
+    buffer of its own, so handing it one layer's slice would copy that
+    layer's every expert (1.21 GB at Qwen3-30B-A3B widths) on each call.
 
     A loop over tiles reads exactly one expert's weights per tile — the
     same traffic shape as the kernel's scalar-prefetched DMA — and never
@@ -121,15 +127,16 @@ def moe_gmm_ragged_ref(rows: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     materialise (n_tiles, d, F) copies, 4.8 GB for one 1024-token prefill
     at Qwen3-30B-A3B widths."""
     n_rows, d = rows.shape
-    e = w_gate.shape[0]
+    e = w_gate.shape[-3]
 
     def tile_ffn(args):
         x, ex = args
         sel = jnp.minimum(ex, e - 1)
+        at = sel if layer is None else (layer, sel)
         xf = x.astype(jnp.float32)
-        g = xf @ w_gate[sel].astype(jnp.float32)
-        u = xf @ w_up[sel].astype(jnp.float32)
-        y = (jax.nn.silu(g) * u) @ w_down[sel].astype(jnp.float32)
+        g = xf @ w_gate[at].astype(jnp.float32)
+        u = xf @ w_up[at].astype(jnp.float32)
+        y = (jax.nn.silu(g) * u) @ w_down[at].astype(jnp.float32)
         return jnp.where(ex < e, y, 0.0).astype(rows.dtype)
 
     tiles = rows.reshape(-1, m_blk, d)

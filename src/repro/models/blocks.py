@@ -62,10 +62,12 @@ def apply_block(cfg: ModelConfig, spec: BlockSpec, p, x: Array, *,
                 valid: Optional[Array] = None,
                 positions3: Optional[Array] = None,
                 gmm_fn=None, dropless: bool = False,
-                moe_dispatch: str = "dense"
+                moe_dispatch: str = "dense", expert_layer=None
                 ) -> Tuple[Array, Optional[dict], dict]:
     """x: (B,S,D) -> (x', new_cache, aux). aux has uniform pytree structure
-    across block kinds so heterogeneous stacks scan cleanly."""
+    across block kinds so heterogeneous stacks scan cleanly.
+    ``expert_layer``: ``p["moe"]`` holds the layer stacks of the routed
+    experts, read at this layer (``moe.apply_moe``'s ``layer``)."""
     h = layers.apply_norm(cfg, p["ln1"], x)
     if spec.is_attention():
         with jax.named_scope("attention"):
@@ -107,7 +109,8 @@ def apply_block(cfg: ModelConfig, spec: BlockSpec, p, x: Array, *,
         if spec.ffn == FFN_MOE:
             out2, aux = moe.apply_moe(cfg, p["moe"], h2, valid=valid,
                                       gmm_fn=gmm_fn, dropless=dropless,
-                                      moe_dispatch=moe_dispatch)
+                                      moe_dispatch=moe_dispatch,
+                                      layer=expert_layer)
         else:
             out2 = layers.apply_mlp(cfg, p["mlp"], h2)
         x = x + out2

@@ -25,8 +25,8 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, blocks, layers
-from repro.models.config import BlockSpec, ModelConfig
+from repro.models import attention, blocks, layers, moe
+from repro.models.config import FFN_MOE, BlockSpec, ModelConfig
 from repro.sharding.partition import shard_hint, shard_seq_hint
 
 Array = jax.Array
@@ -42,6 +42,19 @@ def _stack_zeros(tree, reps: int):
     preserving non-zero init values (e.g. the xLSTM stabilizer m=-inf)."""
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (reps,) + x.shape), tree)
+
+
+def _without_experts(bp: dict) -> dict:
+    """A MoE block's parameters without its routed experts' weights."""
+    return {**bp, "moe": {k: v for k, v in bp["moe"].items()
+                          if k not in moe.EXPERT_LEAVES}}
+
+
+def _with_experts(bp: dict, stacked: dict) -> dict:
+    """``bp`` with the routed experts' weights of the layer stack
+    ``stacked``, whole: the block reads them in place at its layer."""
+    return {**bp, "moe": {**bp["moe"], **{k: stacked["moe"][k]
+                                          for k in moe.EXPERT_LEAVES}}}
 
 
 def sinusoidal_positions(n: int, d: int) -> Array:
@@ -69,6 +82,16 @@ class DecoderModel:
         # forward so the backward pass recomputes activations — required to
         # fit 4k-seq training batches in 16 GB HBM.
         self.remat = remat
+
+    def _experts_in_place(self, spec: BlockSpec, x: Array, gmm_fn,
+                          moe_dispatch: str) -> bool:
+        """Whether block ``spec`` over x (B, S, D) reads its routed experts
+        in place from its segment's layer stacks, by (layer, expert),
+        rather than from a per-layer slice: the ragged jnp tile loop's
+        operand would be a buffer of its own, one copy of every expert of
+        the layer per call (1.21 GB at Qwen3-30B-A3B widths)."""
+        return spec.ffn == FFN_MOE and moe.reads_experts_in_place(
+            self.cfg, x.shape[0], x.shape[1], gmm_fn, moe_dispatch)
 
     # -- init ---------------------------------------------------------------
 
@@ -181,21 +204,32 @@ class DecoderModel:
 
         for s, (pattern, reps) in enumerate(self.segments):
             seg = params["segments"][s]["pattern"]
+            # scanned blocks that read their experts in place: the scan
+            # carries the layer index in place of those three leaves
+            in_place = [reps > 1 and not self.unroll
+                        and self._experts_in_place(sp, x, gmm_fn,
+                                                   moe_dispatch)
+                        for sp in pattern]
 
             def body(h, xs):
-                ps, cs = xs
+                ps, cs, layer = xs
                 new_cs, auxes = [], []
                 for p_idx, sp in enumerate(pattern):
+                    bp, ex_layer = ps[p_idx], None
+                    if in_place[p_idx]:
+                        bp, ex_layer = _with_experts(bp, seg[p_idx]), layer
+
                     def block_fn(bp, h_, sp=sp, c_=(cs[p_idx] if cs is not None
-                                                    else None)):
+                                                    else None),
+                                 ex_layer=ex_layer):
                         return blocks.apply_block(
                             cfg, sp, bp, h_, positions=positions,
                             offset=offset, cache=c_, enc_out=enc_out,
                             valid=valid, gmm_fn=gmm_fn, dropless=dropless,
-                            moe_dispatch=moe_dispatch)
+                            moe_dispatch=moe_dispatch, expert_layer=ex_layer)
                     if self.remat and cs is None:
                         block_fn = jax.checkpoint(block_fn)
-                    h, nc, aux = block_fn(ps[p_idx], h)
+                    h, nc, aux = block_fn(bp, h)
                     h = shard_seq_hint(h)
                     new_cs.append(nc)
                     auxes.append(aux)
@@ -210,7 +244,7 @@ class DecoderModel:
                           for t in seg]
                     cs = ([jax.tree_util.tree_map(lambda a: a[r], t)
                            for t in cs_stacked] if cache is not None else None)
-                    x, (ncs, auxes) = body(x, (ps, cs))
+                    x, (ncs, auxes) = body(x, (ps, cs, None))
                     if cache is not None:
                         ncs_acc.append(ncs)
                     if auxes_acc is None:
@@ -232,20 +266,20 @@ class DecoderModel:
                 ps = [jax.tree_util.tree_map(lambda a: a[0], t) for t in seg]
                 cs = ([jax.tree_util.tree_map(lambda a: a[0], t)
                        for t in cs_stacked] if cache is not None else None)
-                x, (ncs, auxes) = body(x, (ps, cs))
+                x, (ncs, auxes) = body(x, (ps, cs, None))
                 if cache is not None:
                     new_cache.append([jax.tree_util.tree_map(
                         lambda a: a[None], t) for t in ncs])
                 auxes_stacked = [jax.tree_util.tree_map(lambda a: a[None], a_)
                                  for a_ in auxes]
             else:
-                xs = (seg, cs_stacked) if cache is not None else (seg, None)
+                seg_xs = [_without_experts(t) if ip else t
+                          for t, ip in zip(seg, in_place)]
+                layer_ids = jnp.arange(reps) if any(in_place) else None
+                x, (ncs, auxes_stacked) = jax.lax.scan(
+                    body, x, (seg_xs, cs_stacked, layer_ids))
                 if cache is not None:
-                    x, (ncs, auxes_stacked) = jax.lax.scan(body, x, xs)
                     new_cache.append(ncs)
-                else:
-                    x, (_, auxes_stacked) = jax.lax.scan(
-                        lambda h, ps: body(h, (ps, None)), x, seg)
             # collect aux in block order: (reps, P, E) -> (reps*P, E)
             counts = jnp.stack([a["expert_counts"] for a in auxes_stacked],
                                axis=1)
@@ -318,19 +352,27 @@ class DecoderModel:
         for b in range(start, start + n):
             s, r, p_idx = self.index_map[b]
             spec = self.specs[b]
-            # slice this block's weights only once the previous block has
-            # run: a loop over expert tiles (kernels.ref) needs its weights
-            # in a buffer of their own, and XLA would otherwise copy every
-            # block's slice up front (7 GB at Qwen3-30B-A3B widths, 6 blocks)
+            # read this block's weights only once the previous block has
+            # run, so that XLA places no block's weight slices (a copy
+            # where the consumer needs a buffer of its own) ahead of the
+            # blocks before it.  Routed experts read in place are not
+            # sliced at all: the tile loop indexes the stack at layer r.
             x, seg = jax.lax.optimization_barrier(
                 (x, params["segments"][s]["pattern"][p_idx]))
-            bp = jax.tree_util.tree_map(lambda a: a[r], seg)
+            if self._experts_in_place(spec, x, gmm_fn, moe_dispatch):
+                ex_layer = r
+                bp = _with_experts(jax.tree_util.tree_map(
+                    lambda a: a[r], _without_experts(seg)), seg)
+            else:
+                ex_layer = None
+                bp = jax.tree_util.tree_map(lambda a: a[r], seg)
             c = (jax.tree_util.tree_map(lambda a: a[r], cache[s][p_idx])
                  if cache is not None else None)
             x, nc, aux = blocks.apply_block(
                 self.cfg, spec, bp, x, positions=positions, offset=offset,
                 cache=c, enc_out=enc_out, valid=valid, gmm_fn=gmm_fn,
-                dropless=dropless, moe_dispatch=moe_dispatch)
+                dropless=dropless, moe_dispatch=moe_dispatch,
+                expert_layer=ex_layer)
             if cache is not None:
                 cache[s][p_idx] = jax.tree_util.tree_map(
                     lambda full, new: full.at[r].set(new.astype(full.dtype)),

@@ -234,23 +234,26 @@ def ragged_dispatch_indices(expert_idx: Array, n_experts: int, m_blk: int,
 
 
 def ragged_ffn_ref(cfg: ModelConfig, p, rows: Array, tile_expert: Array,
-                   m_blk: int) -> Array:
+                   m_blk: int, layer=None) -> Array:
     """jnp fallback for the ragged grouped matmul (same contract as
     kernels/ops.ragged_gmm_fn): per row tile, the owning expert's fused
     SwiGLU FFN; sentinel tiles produce zeros. Thin adapter over the single
     oracle in kernels/ref.py — its per-tile weight gather mirrors the
-    kernel's scalar-prefetched DMA (only touched experts' weights read)."""
+    kernel's scalar-prefetched DMA (only touched experts' weights read).
+    With ``layer``, ``p``'s expert weights are the layer stack and are
+    read in place at that layer."""
     from repro.kernels.ref import moe_gmm_ragged_ref
     return moe_gmm_ragged_ref(rows, p["w_gate"], p["w_up"], p["w_down"],
-                              tile_expert, m_blk)
+                              tile_expert, m_blk, layer=layer)
 
 
 def _dispatch_gmm_combine_ragged(cfg: ModelConfig, p, xf: Array, idx: Array,
-                                 w: Array, n_local: int, gmm_fn):
+                                 w: Array, n_local: int, gmm_fn, layer=None):
     """Ragged counterpart of ``_dispatch_gmm_combine``: gather tokens into
     the expert-sorted tile-aligned (rows, d) buffer, ragged grouped GEMM,
     weighted combine. Inherently dropless — rows scale with the routed
-    assignments, not E × T. idx entries >= n_local are masked out."""
+    assignments, not E × T. idx entries >= n_local are masked out.
+    ``layer`` (jnp tile loop only) indexes stacked expert weights."""
     e = cfg.moe
     t, d = xf.shape
     m_blk, n_rows = ragged_tile_rows(t * e.top_k, n_local)
@@ -261,7 +264,10 @@ def _dispatch_gmm_combine_ragged(cfg: ModelConfig, p, xf: Array, idx: Array,
         jnp.where(keep, slot, n_rows)
     ].set(tok_ids, mode="drop")
     rows = xf[tok_of_row]                              # (n_rows, d)
-    y = (gmm_fn or ragged_ffn_ref)(cfg, p, rows, tile_expert, m_blk)
+    if layer is None:
+        y = (gmm_fn or ragged_ffn_ref)(cfg, p, rows, tile_expert, m_blk)
+    else:
+        y = ragged_ffn_ref(cfg, p, rows, tile_expert, m_blk, layer=layer)
     out = _combine_topk(y, slot, keep, w)
     return out, counts, jnp.zeros((), jnp.int32)
 
@@ -302,10 +308,24 @@ def _sharded_moe_plan(cfg: ModelConfig, b: int, s: int):
     return mesh, tuple(batch), tuple(tp), b_n, tp_n, mode
 
 
+# the routed experts' weights, which a layer stack can hand over whole
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def reads_experts_in_place(cfg: ModelConfig, b: int, s: int, gmm_fn,
+                           moe_dispatch: str) -> bool:
+    """Whether ``apply_moe`` over a (b, s) input reads its routed experts
+    through the jnp tile loop outside shard_map, so that it can take the
+    layer stack's expert weights whole together with a ``layer`` index."""
+    return (moe_dispatch == "ragged" and gmm_fn is None
+            and _sharded_moe_plan(cfg, b, s) is None)
+
+
 def apply_moe(cfg: ModelConfig, p, x: Array, *,
               valid: Optional[Array] = None,
               gmm_fn=None, dropless: bool = False,
-              moe_dispatch: str = "dense") -> Tuple[Array, dict]:
+              moe_dispatch: str = "dense",
+              layer=None) -> Tuple[Array, dict]:
     """x: (B, S, d) -> (out (B,S,d), aux). ``gmm_fn`` optionally overrides the
     per-expert GEMM (the Pallas kernels plug in here; a dense gmm_fn takes
     the (E, C, d) capacity buffer, a ragged one — marked ``fn.ragged=True``
@@ -329,7 +349,11 @@ def apply_moe(cfg: ModelConfig, p, x: Array, *,
     weight block is gathered once per layer at the shard_map boundary, and
     one psum over the expert axis combines contributions. This replaces the
     XLA-auto path that re-materialized the global (E, C, d) capacity buffer
-    with per-layer all-gathers (13.3 TB/device on qwen3-moe prefill_32k)."""
+    with per-layer all-gathers (13.3 TB/device on qwen3-moe prefill_32k).
+
+    ``layer``: ``p``'s ``EXPERT_LEAVES`` are the segment's layer stacks
+    (reps, E, ...) and the tile loop reads them at this layer, in place
+    (only where ``reads_experts_in_place``)."""
     e = cfg.moe
     if moe_dispatch not in ("dense", "ragged"):
         raise ValueError(f"unknown moe_dispatch {moe_dispatch!r}")
@@ -357,7 +381,7 @@ def apply_moe(cfg: ModelConfig, p, x: Array, *,
         with jax.named_scope("moe.experts"):
             if moe_dispatch == "ragged":
                 out, counts, dropped = _dispatch_gmm_combine_ragged(
-                    cfg, p, xf, idx, w, e.n_experts, gmm_fn)
+                    cfg, p, xf, idx, w, e.n_experts, gmm_fn, layer)
             else:
                 cap = t if dropless else capacity(cfg, t)
                 out, counts, dropped = _dispatch_gmm_combine(

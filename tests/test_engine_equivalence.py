@@ -14,6 +14,7 @@ import pytest
 from conftest import tiny_dense, tiny_hybrid, tiny_mla, tiny_moe, tiny_xlstm
 from repro.core.base import make_scheduler
 from repro.core.plan import RequestState
+from repro.models import moe
 from repro.models.model import DecoderModel
 from repro.serving.engine import Engine
 
@@ -21,13 +22,13 @@ SCHEDS = ["continuous", "chunked", "layered", "hybrid", "static"]
 
 
 def generate(cfg, sched_name, prompts, max_new=6, moe_dispatch="ragged",
-             **sched_kw):
+             gmm_fn=None, **sched_kw):
     model = DecoderModel(cfg)
     params = model.init(jax.random.PRNGKey(0))
     sched = make_scheduler(sched_name, model.n_blocks, n_slots=4,
                            quantum=8, token_budget=16, **sched_kw)
     eng = Engine(model, params, sched, n_slots=4, max_len=128,
-                 moe_dispatch=moe_dispatch)
+                 moe_dispatch=moe_dispatch, gmm_fn=gmm_fn)
     for p in prompts:
         eng.submit(p, max_new)
     eng.run()
@@ -75,6 +76,22 @@ def test_moe_engine_dense_vs_ragged_dispatch(sched):
     dense = generate(cfg, sched, PROMPTS, moe_dispatch="dense")
     ragged = generate(cfg, sched, PROMPTS, moe_dispatch="ragged")
     assert ragged == dense, f"{sched}: ragged dispatch changed outputs"
+
+
+@pytest.mark.parametrize("sched", ["layered", "chunked"])
+@pytest.mark.parametrize("pattern", [(), ("gqa", "local_gqa")],
+                         ids=["uniform", "two-block-pattern"])
+def test_moe_engine_experts_in_place_matches_sliced(sched, pattern):
+    """Ragged MoE blocks read their routed experts in place from the layer
+    stack, by (layer, expert), in the decode scan and in packed prefill
+    groups.  Handing the same jnp tile loop as ``gmm_fn`` keeps it on
+    per-layer slices of the stack: the token streams are identical."""
+    cfg = tiny_moe(n_layers=4, mixer_pattern=pattern, local_window=16)
+    prompts = PROMPTS + [list(range(3, 60))]
+    in_place = generate(cfg, sched, prompts, max_new=8)
+    sliced = generate(cfg, sched, prompts, max_new=8,
+                      gmm_fn=moe.ragged_ffn_ref)
+    assert in_place == sliced
 
 
 def test_engine_matches_naive_reference():

@@ -114,6 +114,46 @@ def test_apply_moe_ragged_matches_dense_dropless():
     assert int(aux_r["dropped"]) == 0
 
 
+@pytest.mark.parametrize("layer", [0, 2, "traced", None])
+def test_ragged_ref_reads_layer_stack_in_place(layer):
+    """``moe_gmm_ragged_ref`` on (reps, E, d, F) stacks at a layer index
+    (static or traced) equals the same call on that layer's slice, bit for
+    bit; without an index it is the per-tile SwiGLU of the owning expert on
+    unstacked weights, with zero rows for sentinel tiles."""
+    from repro.kernels.ref import moe_gmm_ragged_ref
+    reps, e, d, f, m_blk, n_tiles = 3, 4, 16, 24, 8, 6
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    wg = jax.random.normal(ks[0], (reps, e, d, f), jnp.bfloat16)
+    wu = jax.random.normal(ks[1], (reps, e, d, f), jnp.bfloat16)
+    wd = jax.random.normal(ks[2], (reps, e, f, d), jnp.bfloat16)
+    rows = jax.random.normal(ks[3], (n_tiles * m_blk, d), jnp.bfloat16)
+    tiles = jnp.asarray([0, 0, 1, 3, e, e], jnp.int32)   # two sentinels
+    ref = jax.jit(moe_gmm_ragged_ref, static_argnames=("m_blk",))
+    at = 1 if layer in ("traced", None) else layer
+    sliced = ref(rows, wg[at], wu[at], wd[at], tiles, m_blk=m_blk)
+    if layer is None:
+        x = np.asarray(rows, np.float32).reshape(n_tiles, m_blk, d)
+        for t, ex in enumerate(np.asarray(tiles)):
+            got = np.asarray(sliced[t * m_blk:(t + 1) * m_blk], np.float32)
+            if ex == e:
+                assert not got.any()
+                continue
+            g = x[t] @ np.asarray(wg[at, ex], np.float32)
+            u = x[t] @ np.asarray(wu[at, ex], np.float32)
+            want = (g / (1 + np.exp(-g)) * u) @ np.asarray(wd[at, ex],
+                                                             np.float32)
+            np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+        return
+    if layer == "traced":
+        stacked = ref(rows, wg, wu, wd, tiles, m_blk=m_blk,
+                      layer=jnp.int32(at))
+    else:
+        stacked = jax.jit(lambda *a: moe_gmm_ragged_ref(
+            *a, m_blk=m_blk, layer=at))(rows, wg, wu, wd, tiles)
+    np.testing.assert_array_equal(np.asarray(stacked, np.float32),
+                                  np.asarray(sliced, np.float32))
+
+
 def test_ragged_tile_rows_bounds():
     for a, e in [(1, 1), (8, 4), (64, 128), (4096, 128), (260_000, 128)]:
         m_blk, rows = moe.ragged_tile_rows(a, e)

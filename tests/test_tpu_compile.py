@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -111,10 +112,10 @@ def test_kernel_compiles_for_v5e(name, cfg, shape):
     assert "tpu_custom_call" in hlo, name
 
 
-def test_one_chip_serving_fits_v5e(cfg, shape):
-    """Weights, the KV slot rows (and as much again in prefix-cache row
-    snapshots) and the temporaries of the largest decode and prefill
-    executables chip_smoke.py runs leave the configuration's headroom."""
+@pytest.fixture(scope="module")
+def serving(cfg, shape):
+    """The weights' and KV rows' bytes, and the compiled largest decode and
+    prefill executables that chip_smoke.py runs."""
     model = DecoderModel(cfg)
     eng = Engine(model, None, "layered", n_slots=1, max_len=PAGE)
     on_chip = functools.partial(jax.tree_util.tree_map,
@@ -136,8 +137,32 @@ def test_one_chip_serving_fits_v5e(cfg, shape):
     prefill = last.lower(params, cache, shape((b, p, cfg.d_model)),
                          shape((b, p), bool), shape((b,), i32),
                          shape((b,), i32), shape((b,), i32)).compile()
-    temp = max(c.memory_analysis().temp_size_in_bytes
-               for c in (decode, prefill))
-    kv = cfg.kv_bytes_per_token() * b * s
-    used = nbytes + kv + temp
-    assert used <= HBM_BYTES - HEADROOM, (nbytes, kv, temp)
+    return {"nbytes": nbytes, "kv": cfg.kv_bytes_per_token() * b * s,
+            "decode": decode, "prefill": prefill}
+
+
+def test_one_chip_serving_fits_v5e(serving):
+    """Weights, the KV slot rows (and as much again in prefix-cache row
+    snapshots) and the temporaries of the largest decode and prefill
+    executables chip_smoke.py runs leave the configuration's headroom."""
+    temp = max(serving[k].memory_analysis().temp_size_in_bytes
+               for k in ("decode", "prefill"))
+    used = serving["nbytes"] + serving["kv"] + temp
+    assert used <= HBM_BYTES - HEADROOM, (serving["nbytes"], serving["kv"],
+                                          temp)
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_routed_experts_read_in_place(name, cfg, serving):
+    """No value in the decode step or the last prefill group has one
+    layer's expert-stack shape, (E, d, F) or (E, F, d): the ragged tile
+    loop reads the layer stack in place rather than a per-layer copy.  The
+    decode step's temporaries stay below one layer's expert weights."""
+    e, d, f = cfg.moe.n_experts, cfg.d_model, cfg.moe.expert_d_ff
+    hlo = serving[name].as_text()
+    copies = re.findall(rf"\b[a-z]+[0-9]*\[(?:{e},{d},{f}|{e},{f},{d})\]", hlo)
+    assert not copies, (name, len(copies))
+    if name == "decode":
+        layer_experts = 3 * e * d * f * jnp.dtype(cfg.param_dtype).itemsize
+        temp = serving[name].memory_analysis().temp_size_in_bytes
+        assert temp < layer_experts, (temp, layer_experts)
